@@ -84,11 +84,12 @@ def acyclic_add_edges_impl(
         backed out; false positives under concurrency are allowed).
 
     stats = {"n_products", "rows_per_product", "row_products", "n_partial",
-    "n_incremental", "deciding_depth"} counts the boolean matmuls the cycle
-    checks executed (summed over sub-batches); row_products is the total
-    number of rows fed through the matmul — the comparable work unit between
-    the methods (rows_per_product is -1 under ``method="auto"``, where
-    sub-batches may mix row widths; row_products stays exact).  n_partial /
+    "n_incremental", "deciding_depth", "n_steps", "n_useful_steps"} counts
+    the boolean matmuls the cycle checks executed (summed over
+    sub-batches); row_products is the total number of rows fed through
+    the matmul — the comparable work unit between the methods
+    (rows_per_product is -1 under ``method="auto"``, where sub-batches
+    may mix row widths; row_products stays exact).  n_partial /
     n_incremental count the sub-batch checks algorithm 2 / the closure cache
     decided — under "auto" they expose what the dispatcher chose.
     deciding_depth is int32[n_shards]: the per-shard deciding hop counts of
@@ -96,6 +97,8 @@ def acyclic_add_edges_impl(
     the engine feeds back into `CostModelPolicy` as its per-shard depth-EMA
     vector (contiguous row blocks map to shards, matching the B-sharded
     scan's partitioning; n_shards=1 collapses to the old scalar).
+    n_steps is the scan's sub-batch steps (all of them run) and
+    n_useful_steps those that held at least one candidate edge.
 
     ``prefer_partial_fn`` overrides the ``method="auto"`` choice (default:
     `dispatch.prefer_partial_from_adj`) and ``prefer_incremental_fn``
@@ -164,6 +167,7 @@ def acyclic_add_edges_impl(
 
         # every branch returns (cyc, closure', dirty', n_products,
         # row_products, chose code, per-shard deciding depths)
+        @jax.named_scope("cycle_check.closure")
         def closure_check(_):
             cfull, n = transitive_closure(adj_t, matmul_impl,
                                           with_stats=True)
@@ -197,6 +201,7 @@ def acyclic_add_edges_impl(
             return (cyc, closure2, dirty2, n, n * jnp.int32(capacity),
                     jnp.int32(CHOSE_CLOSURE), zero_depths)
 
+        @jax.named_scope("cycle_check.partial")
         def partial_check(_):
             cyc, n, decided_at = snapshot.partial_cycle_check(
                 adj_t, u_slot, v_slot, cand, p_impl, with_stats=True,
@@ -206,6 +211,7 @@ def acyclic_add_edges_impl(
             return (cyc, closure, dirty2, n, n * jnp.int32(b_sub),
                     jnp.int32(CHOSE_PARTIAL), shard_depths(decided_at))
 
+        @jax.named_scope("cycle_check.incremental")
         def incremental_check(_):
             # lazy rebuild on a dirty cache (charged as closure products),
             # then the B^2-bit-read check and the rank-B fold-in; always
@@ -277,13 +283,13 @@ def acyclic_add_edges_impl(
         adj_n = bitset.scatter_clear_bits(adj_t, u_slot, v_slot, reject)
         ok = already | (cand & ~cyc)
         return (adj_n, closure_n, dirty_n), \
-            (ok, n_products, row_products, chose, depths)
+            (ok, n_products, row_products, chose, depths, jnp.any(cand))
 
     carry0 = (state.adj, cache.closure, cache.dirty) if cached else \
         (state.adj, jnp.zeros((0, 0), jnp.uint32), jnp.asarray(True))
     (adj, closure_f, dirty_f), \
-        (oks, n_products, row_products, chose, depths) = jax.lax.scan(
-            step, carry0, (us_r, vs_r, valid_r))
+        (oks, n_products, row_products, chose, depths, useful) = \
+        jax.lax.scan(step, carry0, (us_r, vs_r, valid_r))
     state = state._replace(adj=adj)
     oks = oks.reshape(b)
     # the insert scan never runs a delete repair: the repair-depth EMA
@@ -306,7 +312,9 @@ def acyclic_add_edges_impl(
              "n_incremental": jnp.sum(chose == CHOSE_INCREMENTAL,
                                       dtype=jnp.int32),
              "n_repair": jnp.int32(0),  # insert checks never delete-repair
-             "deciding_depth": deciding_depth}
+             "deciding_depth": deciding_depth,
+             "n_steps": jnp.int32(subbatches),
+             "n_useful_steps": jnp.sum(useful, dtype=jnp.int32)}
     if cached:
         return state, oks, out_cache, stats
     return state, oks, stats

@@ -184,6 +184,7 @@ def default_region(capacity: int) -> int:
     return align_region(min(capacity, DEFAULT_REGION), capacity)
 
 
+@jax.named_scope("summary_upkeep")
 def build_summary(tiles: jax.Array, capacity: int) -> jax.Array:
     """Per-tile occupancy bitmap of ``tiles`` embedded in the full
     capacity's tile grid — tiles beyond the window are empty under the
@@ -439,6 +440,7 @@ class CacheDelta(NamedTuple):
                 jnp.concatenate([self.rem_mask, self.clear_mask]))
 
 
+@jax.named_scope("delete_repair")
 def affected_rows(closure: jax.Array, seeds: jax.Array,
                   mask: jax.Array) -> jax.Array:
     """bool[C]: rows whose reach sets a removal at ``seeds`` can shrink —
@@ -454,6 +456,7 @@ def affected_rows(closure: jax.Array, seeds: jax.Array,
     return jnp.any((anc | is_seed) & mask[None, :], axis=1)
 
 
+@jax.named_scope("delete_repair")
 def masked_delete_scan(adj_after: jax.Array, closure: jax.Array,
                        affected: jax.Array, hop_impl=None,
                        square: bool = False):
@@ -795,6 +798,7 @@ def chunked_update_impl(block_rows: int = 1024) -> ClosureUpdateImpl:
     return impl
 
 
+@jax.named_scope("insert_fold")
 def insert_update(closure: jax.Array, u_slots: jax.Array,
                   v_slots: jax.Array, accepted: jax.Array,
                   update_impl: Optional[ClosureUpdateImpl] = None
@@ -846,6 +850,7 @@ def insert_update(closure: jax.Array, u_slots: jax.Array,
     return impl(closure, bitset.pack_bits(mask), rows)
 
 
+@jax.named_scope("insert_fold")
 def insert_update_tiled(closure: TiledClosure, u_slots: jax.Array,
                         v_slots: jax.Array, accepted: jax.Array,
                         update_impl: Optional[ClosureUpdateImpl] = None):

@@ -82,6 +82,7 @@ def grow_state(state: DagState, new_capacity: int) -> DagState:
     )
 
 
+@jax.named_scope("slot_lookup")
 def lookup_slots(state: DagState, keys: jax.Array):
     """keys int32[B] -> (slot int32[B], found bool[B])."""
     m = state.alive[None, :] & (state.keys[None, :] == keys[:, None])
@@ -311,7 +312,8 @@ def apply_op_batch_impl(state: DagState, op: jax.Array, a: jax.Array,
     res = jnp.where(op == REMOVE_EDGE, r, res)
     stats = {"n_products": z, "rows_per_product": 0, "row_products": z,
              "n_partial": z, "n_incremental": z, "n_repair": z,
-             "deciding_depth": jnp.zeros((n_shards,), jnp.int32)}
+             "deciding_depth": jnp.zeros((n_shards,), jnp.int32),
+             "n_steps": z, "n_useful_steps": z}
     if acyclic:
         out = acyclic_mod.acyclic_add_edges_impl(
             state, a, b, valid=op == ADD_EDGE, subbatches=subbatches,

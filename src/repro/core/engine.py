@@ -150,7 +150,9 @@ class ReachStats(NamedTuple):
     ``n_repair`` counts the delete-repair commits of the call (masked
     affected-row re-derivations that kept the cache clean through a
     removal); their products/rows are included in ``n_products`` /
-    ``row_products``.
+    ``row_products``.  ``n_steps`` counts the insert scan's sub-batch
+    steps and ``n_useful_steps`` those holding at least one candidate
+    edge (the rest are padding the scan runs anyway).
     """
 
     n_products: jax.Array      # int32: boolean matmuls executed
@@ -159,27 +161,47 @@ class ReachStats(NamedTuple):
     n_incremental: jax.Array   # int32: sub-batch checks the cache decided
     deciding_depth: jax.Array  # int32[S]: last partial check's hop counts
     n_repair: jax.Array        # int32: delete-repair commits of this call
+    n_steps: jax.Array         # int32: insert-scan sub-batch steps run
+    n_useful_steps: jax.Array  # int32: of those, steps with a candidate
 
     @classmethod
     def zeros(cls, n_shards: int = 1) -> "ReachStats":
         z = jnp.int32(0)
-        return cls(z, z, z, z, jnp.zeros((n_shards,), jnp.int32), z)
+        return cls(z, z, z, z, jnp.zeros((n_shards,), jnp.int32), z, z, z)
 
     @classmethod
     def from_raw(cls, stats: dict) -> "ReachStats":
         return cls(stats["n_products"], stats["row_products"],
                    stats["n_partial"], stats["n_incremental"],
-                   stats["deciding_depth"], stats["n_repair"])
+                   stats["deciding_depth"], stats["n_repair"],
+                   stats["n_steps"], stats["n_useful_steps"])
+
+
+# the scalar counts of `ReachStats`, in the order `tally_of` packs them
+TALLY_COUNTS = ("n_products", "row_products", "n_repair", "n_incremental",
+                "n_partial", "n_steps", "n_useful_steps")
 
 
 class OpResult(NamedTuple):
     """Result of one engine call: per-row ok bits, the number of vertex
     adds this call dropped for capacity (serving backpressure signal), and
-    the cycle-check stats (zeros when no reachability check ran)."""
+    the cycle-check stats (zeros when no reachability check ran).
+    ``tally`` is `tally_of` the result where a compiled step packed it
+    (`repro.replica.Primary` with ``jit=True``), else None."""
 
     ok: jax.Array          # bool[B]
     n_overflow: jax.Array  # int32: adds dropped for capacity, this call
     stats: ReachStats
+    tally: Optional[jax.Array] = None
+
+
+def tally_of(res: OpResult) -> jax.Array:
+    """int32[B + len(TALLY_COUNTS)]: the call's ok bits, then its
+    `ReachStats` counts — one array, so a host that waits on the answer
+    copies the work accounting in the same transfer."""
+    counts = jnp.stack([getattr(res.stats, k) for k in TALLY_COUNTS])
+    return jnp.concatenate([res.ok.astype(jnp.int32),
+                            counts.astype(jnp.int32)])
 
 
 # ----------------------------------------------------------- configuration

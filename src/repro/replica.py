@@ -48,6 +48,7 @@ lives in tests/test_faults.py and tests/test_chaos.py.
 """
 from __future__ import annotations
 
+import functools
 import io
 import logging
 import os
@@ -63,7 +64,7 @@ import numpy as np
 from repro.core import bitset, closure_cache
 from repro.core import dag as dag_mod
 from repro.core.closure_cache import CacheDelta
-from repro.core.engine import DagEngine, OpResult
+from repro.core.engine import DagEngine, OpResult, tally_of
 
 logger = logging.getLogger(__name__)
 
@@ -220,12 +221,14 @@ def coalesce_entries(entries: Sequence[LogEntry]) -> List[LogEntry]:
 # functions the eager path calls beside the engine) CSEs away instead of
 # doubling the work, and a fixed-shape writer tick is four compiled
 # calls.  `jax.jit` caches per (capacity, config) structure — the serving
-# front-end's padded phases hit the cache every tick.
+# front-end's padded phases hit the cache every tick.  Each step's
+# `OpResult` carries its `tally` (ok bits and work counts in one array),
+# so a caller that waits on the answer copies both at once.
 
 @jax.jit
 def _add_vertices_step(engine, keys, valid):
     engine, res = engine.add_vertices(keys, valid=valid)
-    return engine, res
+    return engine, res._replace(tally=tally_of(res))
 
 
 @jax.jit
@@ -233,7 +236,8 @@ def _add_edges_step(engine, us, vs, valid):
     engine, res = engine.add_edges_acyclic(us, vs, valid=valid)
     u_slot, _ = dag_mod.lookup_slots(engine.state, us)
     v_slot, _ = dag_mod.lookup_slots(engine.state, vs)
-    return engine, res, CacheDelta.edges_added(u_slot, v_slot, res.ok)
+    return engine, res._replace(tally=tally_of(res)), \
+        CacheDelta.edges_added(u_slot, v_slot, res.ok)
 
 
 @jax.jit
@@ -241,7 +245,7 @@ def _remove_edges_step(engine, us, vs, valid):
     _, _, delta = dag_mod.remove_edges_delta(engine.state, us, vs,
                                              valid=valid)
     engine, res = engine.remove_edges(us, vs, valid=valid)
-    return engine, res, delta
+    return engine, res._replace(tally=tally_of(res)), delta
 
 
 @jax.jit
@@ -249,7 +253,7 @@ def _remove_vertices_step(engine, keys, valid):
     _, _, delta = dag_mod.remove_vertices_delta(engine.state, keys,
                                                 valid=valid)
     engine, res = engine.remove_vertices(keys, valid=valid)
-    return engine, res, delta
+    return engine, res._replace(tally=tally_of(res)), delta
 
 
 # ------------------------------------------------------------------ writer
@@ -325,6 +329,7 @@ class Primary:
             crc = entry_crc(epoch, grow_to, prev, host)
             self.log.append(LogEntry(epoch, grow_to, host, prev, crc))
 
+    @functools.partial(jax.profiler.annotate_function, name="log.flush")
     def flush(self, coalesce: bool = True) -> List[LogEntry]:
         """Ship every staged delta to the host log in one blocking copy.
 

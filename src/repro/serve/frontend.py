@@ -37,8 +37,11 @@ decision and the final adjacency/closure exactly.
 from __future__ import annotations
 
 import asyncio
+import bisect
 import collections
 import dataclasses
+import functools
+import itertools
 import logging
 import time
 from typing import Deque, Dict, Hashable, List, Optional, Tuple
@@ -50,7 +53,7 @@ import numpy as np
 from repro.core import closure_cache
 from repro.core import dag as dag_mod
 from repro.core.dispatch import validate_choice
-from repro.core.engine import DagEngine
+from repro.core.engine import TALLY_COUNTS, DagEngine, OpResult, tally_of
 from repro.replica import (CorruptLogError, LogEntry, Primary, Replica,
                            ReplicaDiverged)
 from repro.serve.admission import AdmissionController, ReplicaHealth
@@ -72,12 +75,23 @@ READERS = ("snapshot", "replica")
 STATUS_OK = 200
 STATUS_SHED = 429
 
+# host spans: `jax.profiler.TraceAnnotation`s, on the profiler's clock
+# beside the device events (about 1 us each when no profiler runs)
+_span = jax.profiler.TraceAnnotation
+
+# writer phases whose `ReachStats` counts `Frontend.stats` sums
+WORK_PHASES = ("remove_vertices", "remove_edges", "add_edges")
+
+# queue-wait histogram bucket edges: 20 per decade, 0.1 ms to 100 s
+WAIT_EDGES_S = tuple(1e-4 * 10 ** (k / 20) for k in range(121))
+
 # jitted read paths — module-level so every Frontend shares the compile
 # cache (keyed on capacity/shape structure)
 _snap_take = jax.jit(lambda e: e.snapshot())
 _snap_read = jax.jit(lambda s, f, t, m: s.reachable(f, t) & m)
 _slot_lookup = jax.jit(lambda e, k: dag_mod.lookup_slots(e.state, k))
 _rep_read = jax.jit(lambda r, u, v, m: r.reachable_slots(u, v) & m)
+_tally_of = jax.jit(tally_of)
 
 
 @jax.jit
@@ -94,6 +108,7 @@ def _rep_apply(rep: Replica, epoch, delta) -> Replica:
                    rep.update_impl, rep.delete_impl)
 
 
+@functools.partial(jax.profiler.annotate_function, name="replica.apply")
 def _advance_replica(rep: Replica, entries: List[LogEntry]) -> Replica:
     """Replay semantics of `Replica.replay` on the compiled apply: the
     same host-side integrity gate (`Replica._admits` — CRC verify,
@@ -120,6 +135,29 @@ class Request:
     tenant: Hashable
     future: Optional[asyncio.Future]
     t_submit: float
+
+
+class WaitHistogram:
+    """Queue waits (seconds) in fixed buckets (`WAIT_EDGES_S`): one
+    bisect per request, and ``stats`` in the cumulative form of the
+    bucket edges (``cumulative[i]`` waits at most ``edges_s[i]``; the
+    ``count`` also holds those past the last edge)."""
+
+    def __init__(self):
+        self.counts = [0] * (len(WAIT_EDGES_S) + 1)
+        self.count = 0
+        self.sum_s = 0.0
+
+    def record(self, wait_s: float) -> None:
+        self.counts[bisect.bisect_left(WAIT_EDGES_S, wait_s)] += 1
+        self.count += 1
+        self.sum_s += wait_s
+
+    @property
+    def stats(self) -> dict:
+        return {"edges_s": list(WAIT_EDGES_S),
+                "cumulative": list(itertools.accumulate(self.counts[:-1])),
+                "count": self.count, "sum_s": self.sum_s}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -243,6 +281,14 @@ class Frontend:
         self.n_resyncs = 0
         self.n_degraded_reads = 0
         self.served_by_tenant: Dict[Hashable, int] = {}
+        # submit -> DRR pick, per request
+        self.queue_wait = WaitHistogram()
+        # per writer phase: the `ReachStats` counts (TALLY_COUNTS) summed
+        self._work = {ph: np.zeros(len(TALLY_COUNTS), np.int64)
+                      for ph in WORK_PHASES}
+        # the engine epoch as of the last tick, kept on the host so that
+        # `stats` never waits on the device
+        self._epoch = int(primary.engine.epoch)
 
     @classmethod
     def create(cls, capacity: int,
@@ -296,6 +342,7 @@ class Frontend:
         self._replicas = [_advance_replica(r, new) for r in self._replicas]
         self._log_cursor = len(p.log)
         self._snap = _snap_take(p.engine)
+        self._epoch = int(p.engine.epoch)
 
     async def stop(self) -> None:
         """Drain the queue (every admitted request gets its response),
@@ -374,8 +421,12 @@ class Frontend:
                     await asyncio.wait_for(self._wakeup.wait(), remaining)
                 except asyncio.TimeoutError:
                     break
-            batch = self.drr.select(self._pending, cfg.batch_size)
-            self._n_queued -= len(batch)
+            with _span("frontend.coalesce"):
+                batch = self.drr.select(self._pending, cfg.batch_size)
+                self._n_queued -= len(batch)
+                picked = time.perf_counter()
+                for req in batch:
+                    self.queue_wait.record(picked - req.t_submit)
             if not batch:
                 continue
             # the jax work runs in a worker thread so the event loop
@@ -399,8 +450,23 @@ class Frontend:
             a[i], b[i], m[i] = r.a, r.b, True
         return jnp.asarray(a), jnp.asarray(b), jnp.asarray(m)
 
+    def _settle(self, phase: str, res: OpResult) -> np.ndarray:
+        """The writer phase's ok bits, from ONE host copy of its
+        `OpResult.tally` (packed by the compiled step; packed here for an
+        eager writer); its work counts are summed per phase."""
+        t = np.asarray(res.tally if res.tally is not None
+                       else _tally_of(res))
+        b = self.config.batch_size
+        if phase in self._work:
+            self._work[phase] += t[b:]
+        return t[:b] != 0
+
     def _commit_sync(self, batch: List[Request]
                      ) -> List[Tuple[Request, Response]]:
+        with _span("frontend.tick", tick=self._tick_no, n=len(batch)):
+            return self._tick(batch)
+
+    def _tick(self, batch: List[Request]) -> List[Tuple[Request, Response]]:
         p = self.primary
         by_kind: Dict[str, List[Request]] = {k: [] for k in KINDS}
         for r in batch:
@@ -422,23 +488,30 @@ class Frontend:
         # first occurrences would spike mid-run latency.  Reads-only
         # ticks skip the writer entirely. ----
         if mutated:
-            keys, _, m = self._pad(rv)
-            ok = np.asarray(p.remove_vertices(keys, valid=m).ok)
+            with _span("writer.remove_vertices"):
+                keys, _, m = self._pad(rv)
+                ok = self._settle("remove_vertices",
+                                  p.remove_vertices(keys, valid=m))
             decisions += [(r, bool(ok[i]), STATUS_OK)
                           for i, r in enumerate(rv)]
-            keys, _, m = self._pad(av)
-            res = p.add_vertices(keys, valid=m)
-            ok = np.asarray(res.ok)
-            shed = self.admission.overflow_shed(ok, np.asarray(m))
+            with _span("writer.add_vertices"):
+                keys, _, m = self._pad(av)
+                ok = self._settle("add_vertices",
+                                  p.add_vertices(keys, valid=m))
+                shed = self.admission.overflow_shed(ok, np.asarray(m))
             decisions += [(r, bool(ok[i]),
                            STATUS_SHED if shed[i] else STATUS_OK)
                           for i, r in enumerate(av)]
-            us, vs, m = self._pad(re_)
-            ok = np.asarray(p.remove_edges(us, vs, valid=m).ok)
+            with _span("writer.remove_edges"):
+                us, vs, m = self._pad(re_)
+                ok = self._settle("remove_edges",
+                                  p.remove_edges(us, vs, valid=m))
             decisions += [(r, bool(ok[i]), STATUS_OK)
                           for i, r in enumerate(re_)]
-            us, vs, m = self._pad(ae)
-            ok = np.asarray(p.add_edges_acyclic(us, vs, valid=m).ok)
+            with _span("writer.add_edges"):
+                us, vs, m = self._pad(ae)
+                ok = self._settle("add_edges",
+                                  p.add_edges_acyclic(us, vs, valid=m))
             decisions += [(r, bool(ok[i]), STATUS_OK)
                           for i, r in enumerate(ae)]
 
@@ -452,9 +525,11 @@ class Frontend:
                     self._advance_one(i, new)
             self._log_cursor = len(p.log)
             if self.config.reader == "snapshot":
-                self._snap = _snap_take(p.engine)
+                with _span("snapshot.take"):
+                    self._snap = _snap_take(p.engine)
 
         epoch = int(p.engine.epoch)
+        self._epoch = epoch
 
         # ---- reads, answered at the tick's frozen version; with every
         # replica down, degrade to a stale-but-correct snapshot and
@@ -463,24 +538,27 @@ class Frontend:
         read_ok = None
         read_epoch = epoch
         if reads:
-            f, t, m = self._pad(reads)
-            rep = None if self.config.reader == "snapshot" \
-                else self._pick_replica(epoch)
-            if rep is not None:
-                # rotate the tick's read batch across replicas; the
-                # router resolves keys to slots off the writer's table
-                # (replicas are slot-addressed on purpose — see replica.py)
-                fs, ff = _slot_lookup(p.engine, f)
-                ts, tf = _slot_lookup(p.engine, t)
-                read_ok = np.asarray(_rep_read(rep, fs, ts, m & ff & tf))
-            else:
-                if self.config.reader != "snapshot":
-                    # degraded: the snapshot answers at ITS epoch —
-                    # frozen and consistent, just possibly behind
-                    self._snap = self._fallback_snap(epoch)
-                    self.n_degraded_reads += len(reads)
-                read_ok = np.asarray(_snap_read(self._snap, f, t, m))
-                read_epoch = int(self._snap.epoch)
+            with _span("reader.read"):
+                f, t, m = self._pad(reads)
+                rep = None if self.config.reader == "snapshot" \
+                    else self._pick_replica(epoch)
+                if rep is not None:
+                    # rotate the tick's read batch across replicas; the
+                    # router resolves keys to slots off the writer's
+                    # table (replicas are slot-addressed on purpose —
+                    # see replica.py)
+                    fs, ff = _slot_lookup(p.engine, f)
+                    ts, tf = _slot_lookup(p.engine, t)
+                    read_ok = np.asarray(_rep_read(rep, fs, ts,
+                                                   m & ff & tf))
+                else:
+                    if self.config.reader != "snapshot":
+                        # degraded: the snapshot answers at ITS epoch —
+                        # frozen and consistent, just possibly behind
+                        self._snap = self._fallback_snap(epoch)
+                        self.n_degraded_reads += len(reads)
+                    read_ok = np.asarray(_snap_read(self._snap, f, t, m))
+                    read_epoch = int(self._snap.epoch)
 
         tick = self._tick_no
 
@@ -493,10 +571,11 @@ class Frontend:
                 self.served_by_tenant[req.tenant] = \
                     self.served_by_tenant.get(req.tenant, 0) + 1
 
-        for req, ok, status in decisions:
-            respond(req, ok, status)
-        for i, req in enumerate(reads):
-            respond(req, bool(read_ok[i]), STATUS_OK, read_epoch)
+        with _span("frontend.respond"):
+            for req, ok, status in decisions:
+                respond(req, ok, status)
+            for i, req in enumerate(reads):
+                respond(req, bool(read_ok[i]), STATUS_OK, read_epoch)
         return out
 
     # ------------------------------------------- replica health machinery
@@ -571,7 +650,8 @@ class Frontend:
         only when more than ``max_staleness`` epochs behind — bounded
         staleness without paying a snapshot per healthy tick."""
         if epoch - int(self._snap.epoch) > self.config.max_staleness:
-            self._snap = _snap_take(self.primary.engine)
+            with _span("snapshot.take"):
+                self._snap = _snap_take(self.primary.engine)
         return self._snap
 
     # ------------------------------------------------------------- helpers
@@ -587,7 +667,9 @@ class Frontend:
                  list(self._replicas), self._log_cursor, len(self.trace),
                  self.n_served, dict(self.served_by_tenant),
                  self.admission.n_shed_overflow, self.n_resyncs,
-                 self.n_degraded_reads)
+                 self.n_degraded_reads,
+                 {ph: w.copy() for ph, w in self._work.items()},
+                 self._epoch)
         batch = [Request(k, 0, 0, "_warmup", None, 0.0)
                  for k in ("remove_vertex", "add_vertex", "remove_edge",
                            "add_edge", "reachable")]
@@ -599,7 +681,7 @@ class Frontend:
         (self.primary.engine, n_log, staged, self._snap, self._replicas,
          self._log_cursor, n_trace, self.n_served, self.served_by_tenant,
          self.admission.n_shed_overflow, self.n_resyncs,
-         self.n_degraded_reads) = saved
+         self.n_degraded_reads, self._work, self._epoch) = saved
         del self.primary.log[n_log:]
         self.primary._staged = staged
         del self.trace[n_trace:]
@@ -610,10 +692,19 @@ class Frontend:
 
     @property
     def stats(self) -> dict:
+        """Host counters, as copies (a snapshot taken mid-run is never
+        mutated by later ticks); reading them never waits on the device.
+        ``queue_wait_s`` is the `WaitHistogram` of submit-to-pick waits;
+        ``engine_work`` sums, per writer phase, the `ReachStats` counts
+        of its calls (`engine.TALLY_COUNTS`)."""
         return {"ticks": self._tick_no, "n_served": self.n_served,
                 "served_by_tenant": dict(self.served_by_tenant),
-                "epoch": int(self.primary.engine.epoch),
+                "epoch": self._epoch,
                 "n_resyncs": self.n_resyncs,
                 "n_degraded_reads": self.n_degraded_reads,
                 "replica_health": [h.stats for h in self._health],
+                "queue_wait_s": self.queue_wait.stats,
+                "engine_work": {
+                    ph: dict(zip(TALLY_COUNTS, map(int, w)))
+                    for ph, w in self._work.items()},
                 **self.admission.stats}
