@@ -84,7 +84,8 @@ def acyclic_add_edges_impl(
         backed out; false positives under concurrency are allowed).
 
     stats = {"n_products", "rows_per_product", "row_products", "n_partial",
-    "n_incremental", "deciding_depth", "n_steps", "n_useful_steps"} counts
+    "n_incremental", "deciding_depth", "n_steps", "n_useful_steps",
+    "n_skipped_steps"} counts
     the boolean matmuls the cycle checks executed (summed over
     sub-batches); row_products is the total number of rows fed through
     the matmul — the comparable work unit between the methods
@@ -97,8 +98,9 @@ def acyclic_add_edges_impl(
     the engine feeds back into `CostModelPolicy` as its per-shard depth-EMA
     vector (contiguous row blocks map to shards, matching the B-sharded
     scan's partitioning; n_shards=1 collapses to the old scalar).
-    n_steps is the scan's sub-batch steps (all of them run) and
-    n_useful_steps those that held at least one candidate edge.
+    n_steps is the sub-batch steps run (one per sub-batch holding a
+    valid row), n_useful_steps those that held at least one candidate
+    edge, and n_skipped_steps the all-padding sub-batches not run.
 
     ``prefer_partial_fn`` overrides the ``method="auto"`` choice (default:
     `dispatch.prefer_partial_from_adj`) and ``prefer_incremental_fn``
@@ -287,34 +289,54 @@ def acyclic_add_edges_impl(
 
     carry0 = (state.adj, cache.closure, cache.dirty) if cached else \
         (state.adj, jnp.zeros((0, 0), jnp.uint32), jnp.asarray(True))
-    (adj, closure_f, dirty_f), \
-        (oks, n_products, row_products, chose, depths, useful) = \
-        jax.lax.scan(step, carry0, (us_r, vs_r, valid_r))
+
+    # Only sub-batches holding a valid row run a step, in their original
+    # order: an all-padding sub-batch has no candidate, so its ok row is
+    # all False and it leaves adj alone.  What it skips is a side effect:
+    # a dirty cache is refreshed at the first live step instead (or stays
+    # dirty, and says so, when none is live).
+    live = jnp.any(valid_r, axis=1)
+    order = jnp.argsort(~live, stable=True)
+    n_live = jnp.sum(live, dtype=jnp.int32)
+
+    def run_live(loop):
+        k, carry, oks, counts, last_depths = loop
+        i = order[k]
+        carry, (ok, n, rp, chose, depths, useful) = step(
+            carry, (us_r[i], vs_r[i], valid_r[i]))
+        partial = chose == CHOSE_PARTIAL
+        counts = counts + jnp.stack(
+            [n, rp, partial, chose == CHOSE_INCREMENTAL, useful]
+        ).astype(jnp.int32)
+        return (k + 1, carry, oks.at[i].set(ok), counts,
+                jnp.where(partial, depths, last_depths))
+
+    _, (adj, closure_f, dirty_f), oks, counts, deciding_depth = \
+        jax.lax.while_loop(
+            lambda loop: loop[0] < n_live, run_live,
+            (jnp.int32(0), carry0, jnp.zeros((subbatches, b_sub), bool),
+             jnp.zeros((5,), jnp.int32), zero_depths))
     state = state._replace(adj=adj)
     oks = oks.reshape(b)
-    # the insert scan never runs a delete repair: the repair-depth EMA
+    # the insert loop never runs a delete repair: the repair-depth EMA
     # rides through unchanged
     out_cache = ClosureCache(closure_f, dirty_f, cache.repair_ema) \
         if cached else None
     if not with_stats:
         return (state, oks, out_cache) if cached else (state, oks)
-    # deciding depth of the LAST sub-batch check algorithm 2 decided: the
-    # freshest measurement for the engine's depth-EMA feedback loop
-    k_idx = jnp.arange(subbatches, dtype=jnp.int32)
-    last = jnp.max(jnp.where(chose == CHOSE_PARTIAL, k_idx, -1))
-    deciding_depth = jnp.where(
-        last >= 0, depths[jnp.maximum(last, 0)], zero_depths
-    ).astype(jnp.int32)
-    stats = {"n_products": jnp.sum(n_products, dtype=jnp.int32),
+    # deciding_depth is the LAST algorithm-2 check's: the freshest
+    # measurement for the engine's depth-EMA feedback loop
+    n_products, row_products, n_partial, n_incremental, n_useful = counts
+    stats = {"n_products": n_products,
              "rows_per_product": rows_per_product,
-             "row_products": jnp.sum(row_products, dtype=jnp.int32),
-             "n_partial": jnp.sum(chose == CHOSE_PARTIAL, dtype=jnp.int32),
-             "n_incremental": jnp.sum(chose == CHOSE_INCREMENTAL,
-                                      dtype=jnp.int32),
+             "row_products": row_products,
+             "n_partial": n_partial,
+             "n_incremental": n_incremental,
              "n_repair": jnp.int32(0),  # insert checks never delete-repair
              "deciding_depth": deciding_depth,
-             "n_steps": jnp.int32(subbatches),
-             "n_useful_steps": jnp.sum(useful, dtype=jnp.int32)}
+             "n_steps": n_live,
+             "n_useful_steps": n_useful,
+             "n_skipped_steps": jnp.int32(subbatches) - n_live}
     if cached:
         return state, oks, out_cache, stats
     return state, oks, stats

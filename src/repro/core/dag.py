@@ -313,7 +313,7 @@ def apply_op_batch_impl(state: DagState, op: jax.Array, a: jax.Array,
     stats = {"n_products": z, "rows_per_product": 0, "row_products": z,
              "n_partial": z, "n_incremental": z, "n_repair": z,
              "deciding_depth": jnp.zeros((n_shards,), jnp.int32),
-             "n_steps": z, "n_useful_steps": z}
+             "n_steps": z, "n_useful_steps": z, "n_skipped_steps": z}
     if acyclic:
         out = acyclic_mod.acyclic_add_edges_impl(
             state, a, b, valid=op == ADD_EDGE, subbatches=subbatches,

@@ -150,9 +150,10 @@ class ReachStats(NamedTuple):
     ``n_repair`` counts the delete-repair commits of the call (masked
     affected-row re-derivations that kept the cache clean through a
     removal); their products/rows are included in ``n_products`` /
-    ``row_products``.  ``n_steps`` counts the insert scan's sub-batch
-    steps and ``n_useful_steps`` those holding at least one candidate
-    edge (the rest are padding the scan runs anyway).
+    ``row_products``.  ``n_steps`` counts the insert loop's sub-batch
+    steps run (one per sub-batch holding a valid row), ``n_useful_steps``
+    those holding at least one candidate edge, and ``n_skipped_steps``
+    the all-padding sub-batches the loop did not run.
     """
 
     n_products: jax.Array      # int32: boolean matmuls executed
@@ -161,25 +162,28 @@ class ReachStats(NamedTuple):
     n_incremental: jax.Array   # int32: sub-batch checks the cache decided
     deciding_depth: jax.Array  # int32[S]: last partial check's hop counts
     n_repair: jax.Array        # int32: delete-repair commits of this call
-    n_steps: jax.Array         # int32: insert-scan sub-batch steps run
+    n_steps: jax.Array         # int32: insert-loop sub-batch steps run
     n_useful_steps: jax.Array  # int32: of those, steps with a candidate
+    n_skipped_steps: jax.Array  # int32: all-padding sub-batches not run
 
     @classmethod
     def zeros(cls, n_shards: int = 1) -> "ReachStats":
         z = jnp.int32(0)
-        return cls(z, z, z, z, jnp.zeros((n_shards,), jnp.int32), z, z, z)
+        return cls(z, z, z, z, jnp.zeros((n_shards,), jnp.int32), z, z, z,
+                   z)
 
     @classmethod
     def from_raw(cls, stats: dict) -> "ReachStats":
         return cls(stats["n_products"], stats["row_products"],
                    stats["n_partial"], stats["n_incremental"],
                    stats["deciding_depth"], stats["n_repair"],
-                   stats["n_steps"], stats["n_useful_steps"])
+                   stats["n_steps"], stats["n_useful_steps"],
+                   stats["n_skipped_steps"])
 
 
 # the scalar counts of `ReachStats`, in the order `tally_of` packs them
 TALLY_COUNTS = ("n_products", "row_products", "n_repair", "n_incremental",
-                "n_partial", "n_steps", "n_useful_steps")
+                "n_partial", "n_steps", "n_useful_steps", "n_skipped_steps")
 
 
 class OpResult(NamedTuple):

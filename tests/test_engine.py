@@ -234,7 +234,8 @@ def test_apply_op_batch_plumbs_matmul_impl_and_stats():
     np.testing.assert_array_equal(np.asarray(res), np.asarray(res3))
     assert set(stats) == {"n_products", "rows_per_product", "row_products",
                           "n_partial", "n_incremental", "n_repair",
-                          "deciding_depth", "n_steps", "n_useful_steps"}
+                          "deciding_depth", "n_steps", "n_useful_steps",
+                          "n_skipped_steps"}
     # non-acyclic path: zero stats, same keys
     _, _, stats0 = dag.apply_op_batch_impl(st, batch.op, batch.a, batch.b,
                                            with_stats=True)
