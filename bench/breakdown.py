@@ -2,18 +2,16 @@
 
     python3 bench/breakdown.py --workload <cell> --seed <n> --seconds <s>
 
-One traced run of the cell, as ``run.py --trace 1`` makes it, that also
-keeps the program's spans and scoped device ops from the trace
-(`harness.spans`) before the trace is removed.  Prints one JSON line:
-``correct``; the window's summary (the traced run's own p50 and p99,
-for the cost of tracing); the cell's per-layer metrics and the span
-metrics (``SPAN_METRICS``, read by their files in ``metrics/``); device
-self time by scope; the top-level device ops split by scope; the ops
-with the most self time outside every scope; device-idle
-milliseconds by innermost program span; and the longest idle gaps,
-each named by the program span it fell in.  This is a tool for
-attributing a cell's time, not a cell: ``run.py`` does not keep the
-spans, so the span metrics are not among the benchmark's.
+One traced run of the cell, as ``run.py --trace 1`` makes it, with the
+program's spans and scoped device ops that the run keeps (``run.spans``,
+`harness.spans`).  Prints one JSON line: ``correct``; the window's
+summary (the traced run's own p50 and p99, for the cost of tracing);
+the cell's per-layer metrics; device self time by scope on each chip;
+and, on the cell's first chip, the top-level device ops split by scope,
+the ops with the most self time outside every scope, device-idle
+milliseconds by innermost program span, and the longest idle gaps, each
+named by the program span it fell in.  This is a tool for attributing a
+cell's time, not a cell.
 """
 import time
 
@@ -30,57 +28,20 @@ ROOT = BENCH_DIR.parent
 sys.path.insert(0, str(BENCH_DIR))
 sys.path.insert(0, str(ROOT / "src"))
 
-SPAN_METRICS = [
-    {"name": "frontend.tick_ms", "unit": "ms"},
-    {"name": "device.idle_in_tick_ms_per_tick", "unit": "ms/tick"},
-    {"name": "engine.cycle_check_ms_per_tick", "unit": "ms/tick"},
-    {"name": "engine.insert_fold_ms_per_tick", "unit": "ms/tick"},
-    {"name": "engine.delete_repair_ms_per_tick", "unit": "ms/tick"},
-    {"name": "engine.summary_upkeep_ms_per_tick", "unit": "ms/tick"},
-]
 
-
-def run_keeping_spans(cell, seed: int, seconds: float, t_start: float,
-                      device, cfg_override: dict = None):
-    """One traced run of ``cell`` (`runner.run_cell`) that keeps the
-    program's spans: they are read from the trace directory when the
-    runner loads it, and handed, with the span readers, to its metric
-    read.  Returns (result, spans, run)."""
-    from harness import runner, spans
-    from harness import trace as trace_mod
-
-    kept = {}
-    load, read_metrics = trace_mod.load, runner.read_metrics
-
-    def load_keeping_spans(trace_dir, *a, **kw):
-        kept["spans"] = spans.load(trace_dir)
-        return load(trace_dir, *a, **kw)
-
-    def read_with_spans(specs, run, *a, **kw):
-        run.spans = kept.get("spans")
-        kept["run"] = run
-        return read_metrics(list(specs) + SPAN_METRICS, run, *a, **kw)
-
-    trace_mod.load = load_keeping_spans
-    runner.read_metrics = read_with_spans
-    try:
-        res = runner.run_cell(cell, seed, seconds, True, t_start, device,
-                              cfg_override)
-    finally:
-        trace_mod.load, runner.read_metrics = load, read_metrics
-    return res, kept["spans"], kept["run"]
-
-
-def report(seed: int, res: dict, sp, run) -> dict:
+def report(seed: int, res: dict, run) -> dict:
     from harness import spans
 
-    window = sp.window
+    window = run.spans.window
+    sp = run.spans.on(run.spans.devices[0])
     return {
         "seed": seed, "correct": res["correct"], "device": res["device"],
         "window": run.summary,
         "metrics": {k: v["value"] for k, v in res["metrics"].items()},
         "ticks_traced": len(spans.ticks_in(sp, window)),
         "scope_ms": spans.scope_ms(sp, window),
+        "scope_ms_by_device": [spans.scope_ms(run.spans.on(d), window)
+                               for d in run.spans.devices],
         "split_by_scope": spans.split_by_scope(sp, window),
         "unscoped_top": spans.top_ops(sp, window, spans.UNSCOPED),
         "idle_by_span_ms": spans.idle_by_span(sp, window),
@@ -108,14 +69,14 @@ def main(argv=None) -> int:
     from repro import compile_cache
 
     compile_cache.enable()
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        print(f"breakdown: no TPU (JAX found {dev.platform})",
-              file=sys.stderr)
+    devices = jax.devices()
+    if devices[0].platform != "tpu" or len(devices) < cell.chips:
+        print(f"breakdown: {cell.chips} TPU chips wanted, JAX found "
+              f"{len(devices)} {devices[0].platform}", file=sys.stderr)
         return 2
-    res, sp, run = run_keeping_spans(cell, args.seed, args.seconds,
-                                     T_START, dev)
-    print(json.dumps(report(args.seed, res, sp, run)), flush=True)
+    res, run = runner.run_cell(cell, args.seed, args.seconds, True,
+                               T_START, devices[:cell.chips])
+    print(json.dumps(report(args.seed, res, run)), flush=True)
     return 0
 
 

@@ -48,10 +48,10 @@ def main(argv=None) -> int:
     import jax
 
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        print(f"control: no TPU (JAX found {dev.platform})",
-              file=sys.stderr)
+    devices = jax.devices()
+    if devices[0].platform != "tpu" or len(devices) < cell.chips:
+        print(f"control: {cell.chips} TPU chips wanted, JAX found "
+              f"{len(devices)} {devices[0].platform}", file=sys.stderr)
         return 2
     control = cell.config["control"]["override"]
     arms = [("program", int(s), None) for s in args.seeds.split(",") if s]
@@ -65,9 +65,9 @@ def main(argv=None) -> int:
         if arm == "fold_left_out":
             def plant():
                 undo.append(faults.leave_out_the_fold())
-        res = runner.run_cell(cell, seed, args.seconds, False,
-                              time.perf_counter(), dev, override,
-                              on_window=plant)
+        res, _ = runner.run_cell(cell, seed, args.seconds, False,
+                                 time.perf_counter(), devices[:cell.chips],
+                                 override, on_window=plant)
         for fn in undo:
             fn()
         print(json.dumps({

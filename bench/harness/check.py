@@ -62,13 +62,16 @@ def replay(ref, trace: Sequence[Tuple[str, int, int, bool]]) -> List[bool]:
     return [ref.apply(kind, a, b) for kind, a, b, _ in trace]
 
 
-def client_mismatches(trace, want, reqs, status, ok, ticks
+def client_mismatches(trace, want, reqs, status, ok, ticks,
+                      read_kinds: Sequence[str] = ("reachable",)
                       ) -> Tuple[int, int, int]:
     """(mutations wrong, reads wrong, trace/answer disagreements).
 
     The trace holds the served requests tick by tick, in commit order;
     the answers a client got carry their tick.  Within a tick, answers
-    are matched to trace entries by (kind, a, b)."""
+    are matched to trace entries by (kind, a, b).  A request whose kind
+    is in ``read_kinds`` (the reference's ``READ_KINDS``) is a read,
+    any other a mutation."""
     served = [i for i in range(len(reqs)) if status[i] == 200]
     served.sort(key=lambda i: ticks[i])
     bad_mut = bad_read = bad_trace = 0
@@ -98,7 +101,7 @@ def client_mismatches(trace, want, reqs, status, ok, ticks
                 bucket.remove(bool(ok[i]))
             else:
                 bucket.pop()
-                if kind == "reachable":
+                if kind in read_kinds:
                     bad_read += 1
                 else:
                     bad_mut += 1

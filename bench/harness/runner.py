@@ -2,10 +2,14 @@
 
 Everything a cell is made of is found by name: the cell in
 ``BENCHMARK.json``, its configuration in ``configs/<config>.json``, its
-traffic mix in ``traffic/<traffic>.json``, the configuration's plain
-reference in ``reference/<name>.py`` and each metric's reader in
-``metrics/<metric>.py`` (a ``read(run)`` function that returns a number,
-or None where it finds nothing to read).
+traffic mix in ``traffic/<traffic>.json``, the mix's generator in
+``generators/<generator>.py`` where the mix names one (`generator`),
+the configuration's plain reference in ``reference/<name>.py`` and each
+metric's reader in ``metrics/<metric>.py`` (a ``read(run)`` function
+that returns a number, or None where it finds nothing to read).
+
+A run uses the cell's ``chips`` devices: a sharded engine gets a mesh
+over them, memory is read from each, and a trace is read on each.
 """
 from __future__ import annotations
 
@@ -18,9 +22,9 @@ import shutil
 import sys
 import tempfile
 import time
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
-from harness import check, loadgen, system, traffic
+from harness import check, loadgen, spans, system, traffic
 from harness import trace as trace_mod
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parents[1]
@@ -59,8 +63,31 @@ def load_cell(bench: dict, name: str, bench_dir=BENCH_DIR) -> Cell:
     bench_dir = pathlib.Path(bench_dir)
     cfg = traffic.load(bench_dir / "configs" / f"{w['config']}.json")
     mix = traffic.load(bench_dir / "traffic" / f"{w['traffic']}.json")
-    return Cell(name, cfg, mix, int(w["chips"]), bench["end_to_end"],
-                bench["per_layer"])
+
+    def of_cell(metrics):       # those without a "workloads" list, or on it
+        return [m for m in metrics if name in m.get("workloads", [name])]
+    return Cell(name, cfg, mix, int(w["chips"]),
+                of_cell(bench["end_to_end"]), of_cell(bench["per_layer"]))
+
+
+@dataclasses.dataclass(frozen=True)
+class Generator:
+    """A traffic mix's generator (see `harness.traffic`)."""
+
+    make_preload: Callable
+    make_schedule: Callable
+    write_preload: Callable
+
+
+def generator(mix: dict, bench_dir=BENCH_DIR) -> Generator:
+    """``generators/<name>.py`` for a mix that names ``"generator"``,
+    else `harness.traffic`; ``write_preload`` defaults to
+    `system.write_preload`."""
+    name = mix.get("generator")
+    mod = traffic if name is None else load_module(
+        pathlib.Path(bench_dir) / "generators" / f"{name}.py")
+    return Generator(mod.make_preload, mod.make_schedule,
+                     getattr(mod, "write_preload", system.write_preload))
 
 
 def merged(base: dict, override: dict) -> dict:
@@ -85,11 +112,12 @@ class Run:
     setup_s: float
     outcome: loadgen.Outcome
     summary: dict
-    resident_bytes: int
-    peak_bytes: int
+    resident_bytes: int                     # summed over the cell's chips
+    peak_bytes_by_device: List[int]
     trace: Optional[trace_mod.Summary] = None
     traced: Optional[tuple] = None          # (lo, hi) in window seconds
     stats_traced: Optional[dict] = None     # front-end counters over it
+    spans: Optional[spans.Spans] = None     # the program's, over the trace
 
 
 def read_metrics(specs: List[dict], run: Run,
@@ -105,10 +133,13 @@ def read_metrics(specs: List[dict], run: Run,
     return out
 
 
-def memory(device) -> tuple:
-    stats = device.memory_stats() or {}
-    return int(stats.get("bytes_in_use", -1)), \
-        int(stats.get("peak_bytes_in_use", -1))
+def memory(devices) -> Tuple[int, List[int]]:
+    """(bytes in use summed over ``devices``, each one's peak); -1 for
+    what a device does not report (the sum, if any does not)."""
+    stats = [d.memory_stats() or {} for d in devices]
+    in_use = [int(s.get("bytes_in_use", -1)) for s in stats]
+    peaks = [int(s.get("peak_bytes_in_use", -1)) for s in stats]
+    return (sum(in_use) if min(in_use) >= 0 else -1), peaks
 
 
 class Profiler:
@@ -168,28 +199,29 @@ class CompileCounter:
         jax.monitoring.unregister_event_duration_listener(self._event)
 
 
-def _window_of(tr: trace_mod.Trace):
-    for name, s, d in tr.host:
+def _window_of(traces: Sequence[trace_mod.Trace]):
+    for name, s, d in traces[0].host:
         if name == "bench.window":
             return s, s + d
     return None
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
-             t_start: float, device, cfg_override: dict = None,
-             bench_dir=BENCH_DIR, on_window=None) -> dict:
-    """Set up, measure, check; returns the result line's object.
+             t_start: float, devices: Sequence, cfg_override: dict = None,
+             bench_dir=BENCH_DIR, on_window=None) -> Tuple[dict, Run]:
+    """Set up on ``devices`` (the cell's chips), measure, check; returns
+    the result line's object and the `Run` its metrics were read from.
 
     ``on_window`` is called once set-up is done, just before the window
     opens (a fault planted there leaves the preload sound)."""
     cfg = merged(cell.config, cfg_override or {})
-    keys, edges = traffic.make_preload(seed, cfg["preload"])
-    sched = traffic.make_schedule(seed, cell.mix, keys, edges, seconds)
-    fe = system.make_frontend(cfg)
-    n_v, n_e = system.preload(fe.primary, keys, edges,
-                              int(cfg["preload"]["batch"]))
-    say(f"preload: {n_v} of {len(keys)} vertices, {n_e} of {len(edges)} "
-        f"edges accepted ({time.perf_counter() - t_start:.1f} s)")
+    gen = generator(cell.mix, bench_dir)
+    preload = gen.make_preload(seed, cfg["preload"])
+    sched = gen.make_schedule(seed, cell.mix, preload, seconds)
+    fe = system.make_frontend(cfg, devices)
+    rejected = gen.write_preload(fe, preload, int(cfg["preload"]["batch"]))
+    say(f"preload: written, {rejected} inserts refused "
+        f"({time.perf_counter() - t_start:.1f} s)")
     prof = None
     hooks = []
     tmp = None
@@ -218,14 +250,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     setup_s, out = asyncio.run(serve())
     compiles.close()
-    resident, peak = memory(device)
+    resident, peaks = memory(devices)
     summary = loadgen.summarize(out)
     say("window: " + json.dumps(summary))
     say(f"compilations inside the window: {compiles.n}; replica health "
         f"{fe.stats['replica_health']}")
-    run = Run(cell, seed, seconds, setup_s, out, summary, resident, peak)
+    run = Run(cell, seed, seconds, setup_s, out, summary, resident, peaks)
     if prof is not None:
-        tr = trace_mod.load(tmp)
+        planes = trace_mod.device_planes(devices)
+        tr = trace_mod.load(tmp, planes)
+        run.spans = spans.load(tmp, planes)
         shutil.rmtree(tmp, ignore_errors=True)
         run.trace = trace_mod.reduce(tr, _window_of(tr))
         run.traced = (hooks[1][0], hooks[2][0])
@@ -233,35 +267,44 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     metrics = read_metrics(cell.per_layer if trace else cell.end_to_end,
                            run, bench_dir)
 
-    numbers = compare(cfg, keys, edges, n_v, n_e, fe, sched, out, seed)
+    numbers = compare(cfg, preload, rejected, fe, sched, out, seed,
+                      bench_dir)
     correct = all(v <= lim for _, v, lim in numbers)
+    dev = devices[0]
     result = {
         "correct": bool(correct),
         "attempted": summary["attempted"],
         "failed": summary["failed"],
         "metrics": metrics,
-        "device": {"platform": device.platform, "kind": device.device_kind,
-                   "count": cell.chips, "memory_peak_bytes": peak},
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": cell.chips, "memory_peak_bytes": max(peaks),
+                   "memory_peak_bytes_by_device": peaks},
     }
     if run.trace is not None:
         result["device"]["busy_s"] = run.trace.busy_s
+        result["device"]["busy_s_by_device"] = run.trace.busy_s_by_device
         result["device"]["window_s"] = run.trace.window_s
         result["breakdown"] = {
-            "device_ops": trace_mod.top(run.trace.by_op),
+            "device_ops": trace_mod.top(trace_mod.mean_by(run.trace.by_op)),
             "idle_gaps": [[n, s] for n, s in run.trace.gaps]}
     result["checks"] = {name: {"value": v, "limit": lim}
                         for name, v, lim in numbers}
-    return result
+    return result, run
 
 
-def compare(cfg, keys, edges, n_v, n_e, fe, sched, out, seed) -> list:
-    """[(name, value, limit)] — see `harness.check`."""
-    ref_mod = load_module(BENCH_DIR / "reference" / f"{cfg['reference']}.py")
-    ref = ref_mod.build(keys, edges)
+def compare(cfg, preload, rejected, fe, sched, out, seed,
+            bench_dir=BENCH_DIR) -> list:
+    """[(name, value, limit)] — see `harness.check`.  The reference is
+    built from the preload (``build(*preload)``); its ``READ_KINDS``
+    (default ``("reachable",)``) are the request kinds that read."""
+    ref_mod = load_module(pathlib.Path(bench_dir) / "reference"
+                          / f"{cfg['reference']}.py")
+    ref = ref_mod.build(*preload)
     t0 = time.perf_counter()
     want = check.replay(ref, fe.trace)
     bad_mut, bad_read, bad_trace = check.client_mismatches(
-        fe.trace, want, sched.reqs, out.status, out.ok, out.tick)
+        fe.trace, want, sched.reqs, out.status, out.ok, out.tick,
+        getattr(ref_mod, "READ_KINDS", ("reachable",)))
     say(f"reference replay of {len(fe.trace)} requests: "
         f"{time.perf_counter() - t0:.1f} s")
     engine = fe.primary.engine
@@ -276,7 +319,7 @@ def compare(cfg, keys, edges, n_v, n_e, fe, sched, out, seed) -> list:
     for err in out.errors[:5]:
         say(f"error: {err}")
     return [
-        ("preload_rejected", (len(keys) - n_v) + (len(edges) - n_e), 0),
+        ("preload_rejected", int(rejected), 0),
         ("unanswered", unanswered, 0),
         ("accept_bits_wrong", bad_mut, 0),
         ("reads_wrong", bad_read, 0),

@@ -5,9 +5,10 @@
 * the program's host spans (``jax.profiler.TraceAnnotation``s: the names
   in ``PROGRAM_SPANS``; ``frontend.tick`` with the ``tick`` and ``n`` it
   was opened with), and the harness's ``bench.window``;
-* every device operation (``XLA Ops`` of ``/device:TPU:0``) with the
-  compiled program it ran in and the innermost named scope of the program
-  (``SCOPES``) it was traced under.
+* every device operation (``XLA Ops`` of each device plane read: one
+  per chip of the cell) with its chip, the compiled program it ran in and
+  the innermost named scope of the program (``SCOPES``) it was traced
+  under.
 
 The scope is not a stat of a device event.  It is the ``op_name`` of the
 instruction in the program's optimized HLO, which the profiler stores in
@@ -16,8 +17,9 @@ program's ``name(id)``, the same name its ``XLA Modules`` events carry).
 The Python reader of the trace does not expose that plane's metadata, so
 ``hlo_op_names`` reads it straight from the protobuf wire format.
 
-The reductions below are what the span readers in ``metrics/`` compute;
-all times are nanoseconds on the profiler's clock.  A reader finds the
+The reductions below are what the span readers in ``metrics/`` compute,
+over one chip's ops (`Spans.on`); all times are nanoseconds on the
+profiler's clock.  A reader finds the
 loaded spans as ``run.spans`` (``of``), and nothing where the run did not
 keep them or the program wrote none.
 """
@@ -59,6 +61,7 @@ class Op:
     start: int
     dur: int
     path: str = ""  # the instruction's op_name
+    device: int = 0  # index of its chip among the planes read
 
     @property
     def end(self) -> int:
@@ -72,6 +75,15 @@ class Spans:
 
     def named(self, name: str) -> List[Span]:
         return [s for s in self.spans if s.name == name]
+
+    @property
+    def devices(self) -> List[int]:
+        """The chips that ran ops, in order (chip 0 where none ran)."""
+        return sorted({o.device for o in self.ops}) or [0]
+
+    def on(self, device: int) -> "Spans":
+        """The host spans with the ops of one chip."""
+        return Spans(self.spans, [o for o in self.ops if o.device == device])
 
     @property
     def window(self) -> Optional[Tuple[int, int]]:
@@ -186,7 +198,10 @@ def _module_at(modules: Sequence[Tuple[str, int, int]], t: int) -> str:
     return ""
 
 
-def load(trace_dir: str, device_plane: str = "/device:TPU:0") -> Spans:
+def load(trace_dir: str, planes: Sequence[str] = ("/device:TPU:0",)
+         ) -> Spans:
+    """The program's spans and the ops of each device plane of
+    ``planes`` (``Op.device`` is the plane's index there)."""
     from jax.profiler import ProfileData
 
     with open(trace_mod.find_xplane(trace_dir), "rb") as f:
@@ -194,31 +209,34 @@ def load(trace_dir: str, device_plane: str = "/device:TPU:0") -> Spans:
     names = hlo_op_names(raw)
     pd = ProfileData.from_serialized_xspace(raw)
     spans: List[Span] = []
-    ops = []
-    modules = []
+    ops: Dict[str, list] = {p: [] for p in planes}
+    modules: Dict[str, list] = {p: [] for p in planes}
     keep = set(PROGRAM_SPANS) | {WINDOW}
     for plane in pd.planes:
-        if plane.name == device_plane:
+        if plane.name in ops:
             for line in plane.lines:
                 if line.name in trace_mod.MODULE_LINES:
-                    modules += [(e.name, int(e.start_ns), int(e.duration_ns))
-                                for e in line.events]
+                    modules[plane.name] += [(e.name, int(e.start_ns),
+                                             int(e.duration_ns))
+                                            for e in line.events]
                 elif line.name in trace_mod.OP_LINES:
-                    ops += [(trace_mod.short_name(e.name), int(e.start_ns),
-                             int(e.duration_ns)) for e in line.events]
+                    ops[plane.name] += [(trace_mod.short_name(e.name),
+                                         int(e.start_ns), int(e.duration_ns))
+                                        for e in line.events]
         elif plane.name.startswith("/host:CPU"):
             for line in plane.lines:
                 spans += [Span(e.name, int(e.start_ns), int(e.duration_ns),
                                dict(e.stats) if e.name == "frontend.tick"
                                else {})
                           for e in line.events if e.name in keep]
-    modules.sort(key=lambda m: m[1])
     out = []
-    for name, s, d in ops:
-        prog = _module_at(modules, s)
-        path = names.get(prog, {}).get(name, "")
-        out.append(Op(name, trace_mod.short_name(prog), scope_of(path), s,
-                      d, path))
+    for device, plane in enumerate(planes):
+        mods = sorted(modules[plane], key=lambda m: m[1])
+        for name, s, d in ops[plane]:
+            prog = _module_at(mods, s)
+            path = names.get(prog, {}).get(name, "")
+            out.append(Op(name, trace_mod.short_name(prog), scope_of(path),
+                          s, d, path, device))
     spans.sort(key=lambda sp: sp.start)
     return Spans(spans, out)
 
@@ -283,8 +301,9 @@ def scope_ms(sp: Spans, window: Tuple[int, int]) -> Dict[str, float]:
 
 def scope_ms_per_tick(run, *scopes: str) -> Optional[float]:
     """Device self milliseconds under ``scopes`` per front-end tick of the
-    traced span (`metric_util.ticks`); None where no op carries a scope
-    (a program without them), 0 where these scopes did not run."""
+    traced span (`metric_util.ticks`), on the chip that spent the most
+    under them; None where no op carries a scope (a program without
+    them), 0 where these scopes did not run."""
     from harness import metric_util
 
     got = of(run)
@@ -294,8 +313,11 @@ def scope_ms_per_tick(run, *scopes: str) -> Optional[float]:
     sp, window = got
     if all(o.scope == UNSCOPED for o in sp.ops):
         return None
-    by = scope_ms(sp, window)
-    return sum(by.get(s, 0.0) for s in scopes) / ticks
+    per_chip = []
+    for d in sp.devices:
+        by = scope_ms(sp.on(d), window)
+        per_chip.append(sum(by.get(s, 0.0) for s in scopes))
+    return max(per_chip) / ticks
 
 
 def split_by_scope(sp: Spans, window: Tuple[int, int], n: int = 5

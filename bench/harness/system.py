@@ -1,29 +1,41 @@
 """The system under test, built from a configuration file.
 
 Only public entry points of the program are used: ``Frontend.create``
-with the configuration's engine and front-end settings, the writer's
-mutators (``fe.primary.add_vertices`` / ``add_edges_acyclic``) for the
-preload, ``Frontend.start`` to ship the preload to the replicas, and
+with the configuration's front-end settings and its whole ``engine``
+block, the program's mesh builder (``core.sharded.make_dag_mesh``) over
+the cell's chips where the engine is sharded, the writer's mutators
+(``fe.primary.add_vertices`` / ``add_edges_acyclic``) for the preload,
+``Frontend.start`` to ship the preload to the replicas, and
 ``Frontend.warmup`` to compile every serving phase.  No internal layout
 (graph state, closure tiles) is built here.
 """
 from __future__ import annotations
 
+import inspect
 from typing import Tuple
 
 import numpy as np
 
 
-def make_frontend(cfg: dict):
+def make_frontend(cfg: dict, devices=None):
+    """The configuration's front end.  Every key of its ``engine`` block
+    goes to ``DagEngine.create`` (through ``Frontend.create``); a key that
+    is not an option there raises ``ValueError``.  A sharded engine
+    (``"backend": "sharded"``) gets a mesh over ``devices``, the cell's
+    chips."""
+    from repro.core import DagEngine, sharded
     from repro.serve import Frontend, FrontendConfig
 
-    eng = cfg["engine"]
-    fe_cfg = FrontendConfig(**cfg["frontend"])
-    return Frontend.create(int(cfg["capacity"]), fe_cfg,
-                           method=eng["method"],
-                           subbatches=int(eng["subbatches"]),
-                           closure_layout=eng["closure_layout"],
-                           closure_region=int(eng["closure_region"]))
+    takes = set(inspect.signature(DagEngine.create).parameters)
+    engine = dict(cfg["engine"])
+    unknown = sorted(set(engine) - (takes - {"capacity", "mesh"}))
+    if unknown:
+        raise ValueError(f"engine keys {unknown} are not options of "
+                         "DagEngine.create")
+    if engine.get("backend") == "sharded":
+        engine["mesh"] = sharded.make_dag_mesh(devices)
+    return Frontend.create(int(cfg["capacity"]),
+                           FrontendConfig(**cfg["frontend"]), **engine)
 
 
 def _batches(cols, batch: int):
@@ -38,6 +50,14 @@ def _batches(cols, batch: int):
             o[:k] = c[lo:lo + k]
         valid = np.arange(batch) < k
         yield [jnp.asarray(o) for o in out], jnp.asarray(valid), valid
+
+
+def write_preload(fe, keys_edges, batch: int) -> int:
+    """The default generator's preload writer: `preload` of its
+    ``(keys, edges)``; returns the inserts the writer refused."""
+    keys, edges = keys_edges
+    n_v, n_e = preload(fe.primary, keys, edges, batch)
+    return (len(keys) - n_v) + (len(edges) - n_e)
 
 
 def preload(primary, keys: np.ndarray, edges: np.ndarray,

@@ -1,15 +1,21 @@
 """Reduce a profiler trace to the numbers the per-layer metrics read.
 
 ``load`` reads the ``.xplane.pb`` that ``jax.profiler`` wrote and splits
-its events into device operations, device modules (one event per
-execution of a compiled program) and host events.  ``reduce`` then
+the events of each of a cell's chips into device operations and device
+modules (one event per execution of a compiled program), beside the
+host events they share: one `Trace` per chip.  ``reduce`` then
 computes, over the traced window:
 
-* ``busy_s``: the union of the intervals in which a device operation
-  ran (operations nest and overlap; the union counts each instant once);
-* ``by_module`` / ``by_op``: device seconds summed by name;
-* ``gaps``: the longest idle intervals of the device, each labelled
-  with the innermost host event running at its midpoint.
+* ``busy_s_by_device``: for each chip, the union of the intervals in
+  which a device operation ran (operations nest and overlap; the union
+  counts each instant once); ``busy_s`` is their mean;
+* ``by_module`` / ``by_op``: for each chip, device seconds summed by
+  name; ``seconds`` reads the slowest chip (a sharded tick waits on its
+  slowest shard), ``mean_by`` the mean over the chips;
+* ``gaps``: the longest idle intervals of any chip, each labelled with
+  the innermost host event running at its midpoint.
+
+On one chip each of these is that chip's own number.
 
 Device events are named by `short_name`: a compiled program by its
 function (``jit__add_edges_step``), an operation by its HLO name, which
@@ -32,6 +38,8 @@ MODULE_LINES = ("XLA Modules",)
 
 @dataclasses.dataclass
 class Trace:
+    """One chip's device events and the host events of the process."""
+
     ops: List[Event]
     modules: List[Event]
     host: List[Event]
@@ -39,21 +47,30 @@ class Trace:
 
 @dataclasses.dataclass
 class Summary:
+    """The traced window's numbers; each ``*_by_device`` list and each of
+    ``by_module`` / ``by_op`` holds one entry per chip of the cell."""
+
     window_s: float
-    busy_s: float
-    by_module: Dict[str, float]
-    by_op: Dict[str, float]
+    busy_s_by_device: List[float]
+    by_module: List[Dict[str, float]]
+    by_op: List[Dict[str, float]]
     gaps: List[Tuple[str, float]]
+
+    @property
+    def busy_s(self) -> float:
+        """Busy seconds, the mean over the chips."""
+        return sum(self.busy_s_by_device) / len(self.busy_s_by_device)
 
     @property
     def idle_pct(self) -> float:
         return 100.0 * (1.0 - self.busy_s / self.window_s)
 
     def seconds(self, table: str, *patterns: str) -> float:
-        """Device seconds of the events whose label holds any pattern."""
+        """Device seconds of the events whose label holds any pattern, on
+        the chip that spent the most on them."""
         rows = self.by_module if table == "module" else self.by_op
-        return sum(s for name, s in rows.items()
-                   if any(p in name for p in patterns))
+        return max(sum(s for name, s in r.items()
+                       if any(p in name for p in patterns)) for r in rows)
 
 
 def short_name(name: str) -> str:
@@ -75,27 +92,37 @@ def find_xplane(trace_dir: str) -> str:
     return found[-1]
 
 
-def load(trace_dir: str, device_plane: str = "/device:TPU:0") -> Trace:
+def device_planes(devices) -> List[str]:
+    """The trace's plane name of each of ``devices``."""
+    return [f"/device:TPU:{d.id}" for d in devices]
+
+
+def load(trace_dir: str, planes: Sequence[str] = ("/device:TPU:0",)
+         ) -> List[Trace]:
+    """One `Trace` per device plane of ``planes``, in that order."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(find_xplane(trace_dir))
-    ops: List[Event] = []
-    modules: List[Event] = []
+    ops: Dict[str, List[Event]] = {p: [] for p in planes}
+    modules: Dict[str, List[Event]] = {p: [] for p in planes}
     host: List[Event] = []
     for plane in pd.planes:
-        if plane.name == device_plane:
+        if plane.name in ops:
             for line in plane.lines:
                 if line.name in OP_LINES:
-                    ops += [(short_name(e.name), int(e.start_ns),
-                             int(e.duration_ns)) for e in line.events]
+                    ops[plane.name] += [(short_name(e.name), int(e.start_ns),
+                                         int(e.duration_ns))
+                                        for e in line.events]
                 elif line.name in MODULE_LINES:
-                    modules += [(short_name(e.name), int(e.start_ns),
-                                 int(e.duration_ns)) for e in line.events]
+                    modules[plane.name] += [(short_name(e.name),
+                                             int(e.start_ns),
+                                             int(e.duration_ns))
+                                            for e in line.events]
         elif plane.name.startswith("/host:CPU"):
             for line in plane.lines:
                 host += [(e.name, int(e.start_ns), int(e.duration_ns))
                          for e in line.events]
-    return Trace(ops, modules, host)
+    return [Trace(ops[p], modules[p], host) for p in planes]
 
 
 def union(intervals: Iterable[Tuple[int, int]]) -> List[Tuple[int, int]]:
@@ -119,21 +146,19 @@ def _host_at(host: Sequence[Event], t: int) -> str:
     return best[0] if best else "idle host"
 
 
-def reduce(tr: Trace, window: Tuple[int, int] = None,
+def reduce(traces: Sequence[Trace], window: Tuple[int, int] = None,
            n_gaps: int = 10) -> Summary:
-    """The traced window's numbers.  ``window`` (start_ns, end_ns) defaults
-    to the span of all events; device events are clipped to it."""
-    everything = tr.ops + tr.modules + tr.host
+    """The traced window's numbers over the chips of ``traces``.
+    ``window`` (start_ns, end_ns) defaults to the span of all events;
+    device events are clipped to it."""
+    host = traces[0].host
+    everything = host + [e for tr in traces for e in tr.ops + tr.modules]
     if window is None:
         if not everything:
             raise ValueError("empty trace and no window")
         window = (min(s for _, s, _ in everything),
                   max(s + d for _, s, d in everything))
     lo, hi = window
-    busy_src = tr.ops if tr.ops else tr.modules
-    spans = union((max(s, lo), min(s + d, hi)) for _, s, d in busy_src
-                  if s < hi and s + d > lo)
-    busy = sum(e - s for s, e in spans)
 
     def by_name(events):
         out: Dict[str, float] = {}
@@ -143,17 +168,31 @@ def reduce(tr: Trace, window: Tuple[int, int] = None,
                 out[name] = out.get(name, 0.0) + clipped / 1e9
         return out
 
+    busy_s = []
     idle = []
-    prev = lo
-    for s, e in spans + [(hi, hi)]:
-        if s > prev:
-            idle.append((s - prev, prev))
-        prev = max(prev, e)
+    for tr in traces:
+        busy_src = tr.ops if tr.ops else tr.modules
+        spans = union((max(s, lo), min(s + d, hi)) for _, s, d in busy_src
+                      if s < hi and s + d > lo)
+        busy_s.append(sum(e - s for s, e in spans) / 1e9)
+        prev = lo
+        for s, e in spans + [(hi, hi)]:
+            if s > prev:
+                idle.append((s - prev, prev))
+            prev = max(prev, e)
     idle.sort(reverse=True)
-    gaps = [(_host_at(tr.host, start + dur // 2), dur / 1e9)
+    gaps = [(_host_at(host, start + dur // 2), dur / 1e9)
             for dur, start in idle[:n_gaps]]
-    return Summary((hi - lo) / 1e9, busy / 1e9, by_name(tr.modules),
-                   by_name(tr.ops), gaps)
+    return Summary((hi - lo) / 1e9, busy_s,
+                   [by_name(tr.modules) for tr in traces],
+                   [by_name(tr.ops) for tr in traces], gaps)
+
+
+def mean_by(rows: Sequence[Dict[str, float]]) -> Dict[str, float]:
+    """Seconds by name, the mean over the chips' tables."""
+    names = dict.fromkeys(name for r in rows for name in r)
+    return {name: sum(r.get(name, 0.0) for r in rows) / len(rows)
+            for name in names}
 
 
 def top(rows: Dict[str, float], n: int = 10) -> List[List]:

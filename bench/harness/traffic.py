@@ -1,8 +1,16 @@
-"""The one traffic generator: preloads and request schedules from data.
+"""The default traffic generator: preloads and request schedules from data.
 
 A configuration's ``preload`` block and a traffic mix's JSON file are
 parameters; this module turns them, with ``--seed``, into the exact
-inputs of a run.  The same seed gives the same inputs.
+inputs of a run.  The same seed gives the same inputs.  A mix that names
+``"generator": "<name>"`` is generated by ``generators/<name>.py``
+instead (`runner.generator`), which provides the same two functions:
+``make_preload(seed, spec)`` returns the preload, a tuple of the
+arguments of the reference's ``build``; ``make_schedule(seed, mix,
+preload, seconds, per_s=None, fresh=None)`` returns a `Schedule`.  A
+generator may also provide ``write_preload(fe, preload, batch)``,
+returning the inserts the writer refused (default:
+`system.write_preload` of this module's ``(keys, edges)``).
 
 Preload (``make_preload``): ``vertices`` keys in a random topological
 order; vertex i gets 1 to ``max_preds`` (uniform) conflict edges from
@@ -45,8 +53,9 @@ class Schedule:
     due: np.ndarray
     reqs: List[Request]
     arrivals: int
-    after: dict     # the generator's view once all are applied: keys (in
-                    # age order), edges written, the next fresh key
+    after: dict     # once all are applied: "preload", that of a window
+                    # that follows (the knee sweep's), and "fresh", its
+                    # first new key
 
 
     def __len__(self) -> int:
@@ -111,13 +120,13 @@ def arrival_times(rng: np.random.Generator, arrivals: dict,
     return np.sort(rng.uniform(0.0, seconds, n))
 
 
-def make_schedule(seed: int, mix: dict, keys: np.ndarray,
-                  edges: np.ndarray, seconds: float,
+def make_schedule(seed: int, mix: dict, preload, seconds: float,
                   per_s: float = None, fresh: int = None) -> Schedule:
     """The window's requests for ``mix`` after the preload (keys, edges).
 
     ``per_s`` overrides the mix's rate and ``fresh`` the first new key;
     the knee sweep chains windows through `Schedule.after`."""
+    keys, edges = preload
     arrivals = dict(mix["arrivals"])
     if per_s is not None:
         arrivals["per_s"] = per_s
@@ -171,6 +180,6 @@ def _transactions(rng, mix, keys, edges, times, tenants, fresh):
             out.append(("remove_vertex", live.popleft(), 0))
         reqs.extend((kind, a, b, tenant) for kind, a, b in out)
         due.extend([float(t_due)] * len(out))
-    after = {"keys": np.asarray(live, np.int32), "edges": edges,
+    after = {"preload": (np.asarray(live, np.int32), edges),
              "fresh": fresh}
     return reqs, due, after

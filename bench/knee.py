@@ -41,7 +41,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     rates = [float(r) for r in args.rates.split(",")]
 
-    from harness import loadgen, runner, system, traffic
+    from harness import loadgen, runner, system
 
     with open(ROOT / "BENCHMARK.json") as f:
         cell = runner.load_cell(json.load(f), args.workload)
@@ -53,27 +53,28 @@ def main(argv=None) -> int:
     from repro import compile_cache
 
     compile_cache.enable()
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        print(f"knee: no TPU (JAX found {dev.platform})", file=sys.stderr)
+    devices = jax.devices()
+    if devices[0].platform != "tpu" or len(devices) < cell.chips:
+        print(f"knee: {cell.chips} TPU chips wanted, JAX found "
+              f"{len(devices)} {devices[0].platform}", file=sys.stderr)
         return 2
     cfg = cell.config
-    keys, edges = traffic.make_preload(args.seed, cfg["preload"])
-    fe = system.make_frontend(cfg)
-    system.preload(fe.primary, keys, edges, int(cfg["preload"]["batch"]))
+    gen = runner.generator(cell.mix)
+    preload = gen.make_preload(args.seed, cfg["preload"])
+    fe = system.make_frontend(cfg, devices[:cell.chips])
+    gen.write_preload(fe, preload, int(cfg["preload"]["batch"]))
 
     async def sweep():
-        nonlocal keys, edges
+        nonlocal preload
         fresh = None
         async with fe:
             fe.warmup()
             runner.say(f"set-up {time.perf_counter() - T_START:.1f} s")
             for i, rate in enumerate(rates):
-                sched = traffic.make_schedule(args.seed + i, cell.mix, keys,
-                                              edges, args.seconds,
-                                              per_s=rate, fresh=fresh)
-                keys, edges = sched.after["keys"], sched.after["edges"]
-                fresh = sched.after["fresh"]
+                sched = gen.make_schedule(args.seed + i, cell.mix, preload,
+                                          args.seconds, per_s=rate,
+                                          fresh=fresh)
+                preload, fresh = sched.after["preload"], sched.after["fresh"]
                 t0 = fe.stats["ticks"]
                 out = await loadgen.drive(fe, sched.due, sched.reqs,
                                           args.seconds)

@@ -74,8 +74,9 @@ def main(argv=None) -> int:
     dev = devices[0]
     runner.say(f"device: {dev.platform} {dev.device_kind} x {cell.chips} "
                f"(of {len(devices)}); jax {jax.__version__}")
-    result = runner.run_cell(cell, args.seed, args.seconds,
-                             bool(args.trace), T_START, dev)
+    result, _ = runner.run_cell(cell, args.seed, args.seconds,
+                                bool(args.trace), T_START,
+                                devices[:cell.chips])
     for name, c in result["checks"].items():
         runner.say(f"{name}: {c['value']} (limit {c['limit']})")
     print(json.dumps(result), flush=True)

@@ -49,9 +49,9 @@ def test_schedule_is_deterministic_per_seed():
     k2, e2 = traffic.make_preload(SEED, spec)
     assert np.array_equal(k1, k2) and np.array_equal(e1, e2)
     m = _mix("retire")
-    s1 = traffic.make_schedule(SEED, m, k1, e1, 2.0)
-    s2 = traffic.make_schedule(SEED, m, k1, e1, 2.0)
-    s3 = traffic.make_schedule(SEED + 1, m, k1, e1, 2.0)
+    s1 = traffic.make_schedule(SEED, m, (k1, e1), 2.0)
+    s2 = traffic.make_schedule(SEED, m, (k1, e1), 2.0)
+    s3 = traffic.make_schedule(SEED + 1, m, (k1, e1), 2.0)
     assert s1.reqs == s2.reqs and np.array_equal(s1.due, s2.due)
     assert s1.reqs != s3.reqs
     # every seed offers the same arrivals and the same set of sizes
@@ -76,7 +76,7 @@ def test_retire_mix_keeps_its_proportions():
                                               "max_preds": 4,
                                               "pred_window": 1024})
     mix = traffic.load(BENCH / "traffic" / "retire.json")
-    s = traffic.make_schedule(SEED, mix, keys, edges, 10.0)
+    s = traffic.make_schedule(SEED, mix, (keys, edges), 10.0)
     kinds = [r[0] for r in s.reqs]
     n_tx = s.arrivals
     assert kinds.count("add_vertex") == kinds.count("remove_vertex") == n_tx
@@ -85,7 +85,7 @@ def test_retire_mix_keeps_its_proportions():
     assert abs(len(s) / n_tx - 6.5) < 0.1
     assert abs(kinds.count("reachable") / len(s) - 0.31) < 0.01
     # FIFO retirement holds the live count
-    assert len(s.after["keys"]) == len(keys)
+    assert len(s.after["preload"][0]) == len(keys)
     # about 5% of the edges are reversed to (t, s)
     rev = cur = 0
     for kind, a, b, _ in s.reqs:
@@ -192,7 +192,7 @@ def test_trace_reduction_on_a_synthetic_event_list():
                ("jit__rep_apply(9)", 5 * ms, 1 * ms),
                ("jit__add_edges_step(7)", 9 * ms, 1 * ms)]
     host = [("flush", 3 * ms, 2 * ms), ("tick", 0, 10 * ms)]
-    s = trace_mod.reduce(trace_mod.Trace(ops, modules, host), (0, 10 * ms))
+    s = trace_mod.reduce([trace_mod.Trace(ops, modules, host)], (0, 10 * ms))
     assert s.window_s == pytest.approx(0.010)
     assert s.busy_s == pytest.approx(0.005)        # [0,3) [5,6) [9,10)
     assert s.idle_pct == pytest.approx(50.0)
@@ -204,7 +204,7 @@ def test_trace_reduction_on_a_synthetic_event_list():
     # the longest gap [6, 9) ms runs under "tick"; [3, 5) under "flush"
     assert s.gaps[0] == ("tick", pytest.approx(0.003))
     assert s.gaps[1] == ("flush", pytest.approx(0.002))
-    clipped = trace_mod.reduce(trace_mod.Trace(ops, modules, host),
+    clipped = trace_mod.reduce([trace_mod.Trace(ops, modules, host)],
                                (1 * ms, 6 * ms))
     assert clipped.busy_s == pytest.approx(0.003)
 
@@ -303,7 +303,7 @@ def _cell(name, per_s):
 def _run(name, per_s, seed=SEED, override=None):
     cfg = dict(TINY, **(override or {}))
     return runner.run_cell(_cell(name, per_s), seed, 2.0, False,
-                           time.perf_counter(), jax.devices()[0], cfg)
+                           time.perf_counter(), jax.devices()[:1], cfg)[0]
 
 
 def test_sound_run_is_correct():
@@ -377,8 +377,8 @@ def test_control_fails():
     breaks one stated guarantee) comes out not correct."""
     cell = _cell("sgt-32k.retire", 40)
     control = cell.config["control"]["override"]
-    res = runner.run_cell(cell, SEED, 2.0, False, time.perf_counter(),
-                          jax.devices()[0], runner.merged(TINY, control))
+    res, _ = runner.run_cell(cell, SEED, 2.0, False, time.perf_counter(),
+                             jax.devices()[:1], runner.merged(TINY, control))
     assert not res["correct"]
     wrong = {k: v["value"] for k, v in res["checks"].items() if v["value"]}
     assert wrong.keys() & {"accept_bits_wrong", "reads_wrong",
@@ -390,9 +390,9 @@ def test_insert_fold_left_out_fails():
     never the closure."""
     undo = []
     try:
-        res = runner.run_cell(
+        res, _ = runner.run_cell(
             _cell("sgt-32k.retire", 40), SEED, 2.0, False,
-            time.perf_counter(), jax.devices()[0], TINY,
+            time.perf_counter(), jax.devices()[:1], TINY,
             on_window=lambda: undo.append(faults.leave_out_the_fold()))
     finally:
         for fn in undo:

@@ -180,8 +180,8 @@ def _run(with_spans=True, stats=True):
     out = loadgen.Outcome(due, due + [0.001, 0.002, 0.004, 0.0],
                           due + 0.1, np.full(4, 200), np.zeros(4, bool),
                           np.ones(4, bool), np.arange(4), 0.02, [])
-    run = runner.Run(None, 1, 20.0, 1.0, out, {}, 0, 0)
-    run.trace = trace_mod.reduce(tr, (0, 20 * MS))
+    run = runner.Run(None, 1, 20.0, 1.0, out, {}, 0, [0])
+    run.trace = trace_mod.reduce([tr], (0, 20 * MS))
     run.traced = (0.0, 0.02)
     if stats:
         start = _stats(10, [5.0] * 100, _work(640, 160, 20, 400))
@@ -244,9 +244,10 @@ def test_benchmark_lists_the_counter_metrics_for_its_cell():
     for name in COUNTER_READERS:
         assert listed[name]["source"] == "program_counter"
         assert listed[name]["workloads"] == ["sgt-32k.retire"]
-    for name in SPAN_READERS:     # read only by breakdown.py
-        assert name not in listed
-        assert (BENCH / "metrics" / f"{name}.py").is_file()
+    for name in SPAN_READERS:
+        assert listed[name]["source"] in ("device_trace", "program_span")
+        assert listed[name]["workloads"] == ["sgt-32k.retire"]
+        assert listed[name]["moves"] == "p50_ms"
 
 
 def test_breakdown_keeps_the_spans_of_a_traced_run():
@@ -264,13 +265,11 @@ def test_breakdown_keeps_the_spans_of_a_traced_run():
                        "subbatches": 64},
             "preload": {"vertices": 256, "max_preds": 4,
                         "pred_window": 64, "batch": 128}}
-    load, read_metrics = trace_mod.load, runner.read_metrics
-    res, sp, run = bd.run_keeping_spans(cell, 2**33 + 5, 4.0,
-                                        time.perf_counter(),
-                                        jax.devices()[0], tiny)
+    res, run = runner.run_cell(cell, 2**33 + 5, 4.0, True,
+                               time.perf_counter(), jax.devices()[:1], tiny)
     assert res["correct"], res["checks"]
-    assert (trace_mod.load, runner.read_metrics) == (load, read_metrics)
-    out = bd.report(5, res, sp, run)
+    sp = run.spans
+    out = bd.report(5, res, run)
     for name in COUNTER_READERS + ("frontend.tick_ms",
                                    "device.idle_in_tick_ms_per_tick"):
         assert out["metrics"][name] is not None, name
